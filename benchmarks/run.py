@@ -46,7 +46,9 @@ def main(argv=None) -> None:
     from benchmarks import (bench_kernels, bench_serving, real_accuracy,
                             roofline, table2_ppa, table3_image)
     from benchmarks.harness import BenchReport, activate_tuning
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     table = activate_tuning(args.tune)
     if table is not None:
         from repro.kernels import autotune

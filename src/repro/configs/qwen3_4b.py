@@ -14,4 +14,5 @@ CONFIG = register_arch(ArchConfig(
     qk_norm=True,
     rope_theta=1000000.0,
     tie_embeddings=True,
+    param_dtype="bfloat16",  # the published checkpoint's dtype; 8 GB on one chip
 ))

@@ -7,8 +7,6 @@
 
 Layering:
 
-  compat.py    — JAX-version shim (CompilerParams / BlockSpec drift);
-                 the only place allowed to touch ``pltpu.*CompilerParams``
   dispatch.py  — backend resolution (auto | pallas | interpret | xla) and
                  per-kernel block-size lookups (measured autotuner table
                  first, static (backend, shape bucket) fallback); the
@@ -23,6 +21,6 @@ Tests validate the kernel bodies in ``interpret`` mode on CPU and pin
 them against ``ref.py``; ``NumericsConfig.backend`` selects the backend
 end-to-end.
 """
-from . import autotune, compat, dispatch, ops, ref
+from . import autotune, dispatch, ops, ref
 
-__all__ = ["autotune", "compat", "dispatch", "ops", "ref"]
+__all__ = ["autotune", "dispatch", "ops", "ref"]

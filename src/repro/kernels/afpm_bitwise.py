@@ -17,7 +17,6 @@ from jax.experimental import pallas as pl
 
 from repro.core.afpm import AFPMConfig, afpm_mult_f32
 
-from . import compat
 
 DEFAULT_BLOCK = (256, 256)
 
@@ -56,10 +55,10 @@ def afpm_bitwise_pallas(
         functools.partial(_kernel, cfg=cfg),
         grid=(x2.shape[0] // bm,),
         in_specs=[
-            compat.block_spec((bm, ncols), lambda i: (i, 0)),
-            compat.block_spec((bm, ncols), lambda i: (i, 0)),
+            pl.BlockSpec((bm, ncols), lambda i: (i, 0)),
+            pl.BlockSpec((bm, ncols), lambda i: (i, 0)),
         ],
-        out_specs=compat.block_spec((bm, ncols), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bm, ncols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, jnp.float32),
         interpret=interpret,
     )(x2, y2)

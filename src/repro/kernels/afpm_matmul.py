@@ -24,8 +24,7 @@ once — arithmetic intensity is identical to a plain matmul while the MXU
 work is 1-3 bf16 passes instead of 6 (fp32 emulation) per tile.
 
 Block sizes default to the substrate's tuning tables via
-``kernels/dispatch.py``; version-portable Pallas construction goes
-through ``kernels/compat.py``.
+``kernels/dispatch.py``.
 """
 from __future__ import annotations
 
@@ -34,8 +33,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -127,14 +126,14 @@ def afpm_matmul_pallas(
             functools.partial(_kernel2d, passes=passes, nk=nk),
             grid=(Mp // bm, Np // bn, nk),
             in_specs=[
-                compat.block_spec((bm, bk), lambda i, j, k: (i, k)),
-                compat.block_spec((bk, bn), lambda i, j, k: (k, j)),
+                pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+                pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
             ],
-            out_specs=compat.block_spec((bm, bn), lambda i, j, k: (i, j)),
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-            scratch_shapes=[compat.vmem((bm, bn), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
             interpret=interpret,
-            compiler_params=compat.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
         )(x, w)
@@ -152,14 +151,14 @@ def afpm_matmul_pallas(
         functools.partial(_kernel_batched, passes=passes, nk=nk),
         grid=(G, Mp // bm, Np // bn, nk),
         in_specs=[
-            compat.block_spec((1, bm, bk), lambda g, i, j, k: (g, i, k)),
-            compat.block_spec((bk, bn), lambda g, i, j, k: (k, j)),
+            pl.BlockSpec((1, bm, bk), lambda g, i, j, k: (g, i, k)),
+            pl.BlockSpec((bk, bn), lambda g, i, j, k: (k, j)),
         ],
-        out_specs=compat.block_spec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
+        out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((G, Mp, Np), jnp.float32),
-        scratch_shapes=[compat.vmem((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(x, w)

@@ -5,6 +5,8 @@ Each ``*_ref`` function defines the exact semantics its kernel must match
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -12,11 +14,17 @@ from repro.core.afpm import AFPMConfig, afpm_mult_f32
 
 
 def split_hi_lo_ref(x: jax.Array):
-    """fp32 -> (hi, lo) bf16 segments; hi = RNE bf16, lo = bf16(x - hi)."""
+    """fp32 -> (hi, lo) bf16 segments; hi = RNE bf16, lo = bf16(x - hi).
+
+    Both roundings are ``lax.reduce_precision``, not an f32 -> bf16 -> f32
+    round trip: XLA may keep such a pair in f32 as excess precision, and on
+    a TPU v5e it did, which zeroed lo and made every pass level compute
+    hi(x) @ hi(w)."""
     x = jnp.asarray(x, jnp.float32)
-    hi = x.astype(jnp.bfloat16)
-    lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, lo
+    to_bf16 = functools.partial(jax.lax.reduce_precision, exponent_bits=8,
+                                mantissa_bits=7)
+    hi = to_bf16(x)
+    return hi.astype(jnp.bfloat16), to_bf16(x - hi).astype(jnp.bfloat16)
 
 
 def afpm_matmul_ref(x: jax.Array, w: jax.Array, passes: int = 3) -> jax.Array:

@@ -28,36 +28,52 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
 from .ref import chunk_decay
 
 DEFAULT_CHUNK = 128
 
 
-def _kernel(x_ref, dt_ref, l_ref, b_ref, c_ref, y_ref, s_ref, *, nc: int):
+def _last_as_col(row, n: int):
+    """The last entry of a (1, Q) row repeated as an (n, 1) column.
+
+    A masked lane sum with one nonzero term, so the value is exact:
+    Mosaic cannot broadcast a (1, 1) slice along sublanes and lanes at
+    once."""
+    Q = row.shape[1]
+    last = jax.lax.broadcasted_iota(jnp.int32, (n, Q), 1) == Q - 1
+    return jnp.sum(jnp.where(last, row, 0.0), axis=1, keepdims=True)
+
+
+def _kernel(x_ref, dt_row_ref, dt_col_ref, l_row_ref, l_col_ref, b_ref,
+            c_ref, y_ref, s_ref, *, nc: int):
     cid = pl.program_id(1)
 
     @pl.when(cid == 0)
     def _reset():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    x = x_ref[0].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0].astype(jnp.float32)      # (Q,)
-    l = l_ref[0].astype(jnp.float32)        # (Q,) log cumulative decay
-    B = b_ref[...].astype(jnp.float32)      # (Q, N)
-    C = c_ref[...].astype(jnp.float32)      # (Q, N)
+    x = x_ref[0].astype(jnp.float32)            # (Q, P)
+    # dt and the log decay l arrive as rows (1, Q) and as columns (Q, 1):
+    # Mosaic tiles the last two dims of a block, so they cannot be (1, Q)
+    # blocks of (H, L) arrays, and the two views spare an in-kernel
+    # lane-to-sublane transpose
+    dt_row = dt_row_ref[0].astype(jnp.float32)  # (1, Q)
+    dt_col = dt_col_ref[0].astype(jnp.float32)  # (Q, 1)
+    l_row = l_row_ref[0].astype(jnp.float32)    # (1, Q) log cumulative decay
+    l_col = l_col_ref[0].astype(jnp.float32)    # (Q, 1)
+    B = b_ref[...].astype(jnp.float32)          # (Q, N)
+    C = c_ref[...].astype(jnp.float32)          # (Q, N)
     Q = x.shape[0]
-
-    l_col = l[:, None]                      # (Q, 1)
 
     # intra-chunk quadratic term: M[t,s] = (C_t.B_s) dt_s e^{l_t-l_s} [t>=s]
     CB = jnp.dot(C, B.T, preferred_element_type=jnp.float32)     # (Q, Q)
     # clamped: only t>=s used (l_t-l_s <= 0); keeps masked region finite
-    ratio = jnp.exp(jnp.minimum(l_col - l[None, :], 0.0))        # e^{l_t-l_s}
+    ratio = jnp.exp(jnp.minimum(l_col - l_row, 0.0))            # e^{l_t-l_s}
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     s_idx = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    M = jnp.where(t_idx >= s_idx, CB * ratio * dt[None, :], 0.0)
+    M = jnp.where(t_idx >= s_idx, CB * ratio * dt_row, 0.0)
     y = jnp.dot(M, x, preferred_element_type=jnp.float32)        # (Q, P)
 
     # inter-chunk carry: y += (C ∘ e^{l_t}) @ S_prev
@@ -65,10 +81,10 @@ def _kernel(x_ref, dt_ref, l_ref, b_ref, c_ref, y_ref, s_ref, *, nc: int):
     y = y + jnp.dot(C * jnp.exp(l_col), S_prev, preferred_element_type=jnp.float32)
 
     # state update: S = e^{l_Q} S_prev + (B ∘ dt e^{l_Q - l_s})^T @ x
-    lQ = l[-1]
-    w = dt * jnp.exp(lQ - l)                                     # (Q,)
-    s_ref[...] = jnp.exp(lQ) * S_prev + jnp.dot(
-        (B * w[:, None]).T, x, preferred_element_type=jnp.float32
+    N = S_prev.shape[0]
+    w = dt_col * jnp.exp(_last_as_col(l_row, Q) - l_col)         # (Q, 1)
+    s_ref[...] = jnp.exp(_last_as_col(l_row, N)) * S_prev + jnp.dot(
+        (B * w).T, x, preferred_element_type=jnp.float32
     )
 
     y_ref[0] = y
@@ -95,23 +111,25 @@ def ssd_scan_pallas(
     xh = jnp.moveaxis(x, 1, 0)      # (H, L, P)
     dth = jnp.moveaxis(dt, 1, 0)    # (H, L)
     lh = jnp.moveaxis(chunk_decay(dt, A, Q), 1, 0)  # (H, L)
+    row_spec = pl.BlockSpec((1, 1, Q), lambda h, c: (h, 0, c))
+    col_spec = pl.BlockSpec((1, Q, 1), lambda h, c: (h, c, 0))
 
     out = pl.pallas_call(
         functools.partial(_kernel, nc=nc),
         grid=(H, nc),
         in_specs=[
-            compat.block_spec((1, Q, P), lambda h, c: (h, c, 0)),
-            compat.block_spec((1, Q), lambda h, c: (h, c)),
-            compat.block_spec((1, Q), lambda h, c: (h, c)),
-            compat.block_spec((Q, N), lambda h, c: (c, 0)),
-            compat.block_spec((Q, N), lambda h, c: (c, 0)),
+            pl.BlockSpec((1, Q, P), lambda h, c: (h, c, 0)),
+            row_spec, col_spec, row_spec, col_spec,
+            pl.BlockSpec((Q, N), lambda h, c: (c, 0)),
+            pl.BlockSpec((Q, N), lambda h, c: (c, 0)),
         ],
-        out_specs=compat.block_spec((1, Q, P), lambda h, c: (h, c, 0)),
+        out_specs=pl.BlockSpec((1, Q, P), lambda h, c: (h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((H, L, P), jnp.float32),
-        scratch_shapes=[compat.vmem((N, P), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-    )(xh, dth, lh, B, C)
+    )(xh, dth[:, None, :], dth[:, :, None], lh[:, None, :], lh[:, :, None],
+      B, C)
     return jnp.moveaxis(out, 0, 1)  # (L, H, P)

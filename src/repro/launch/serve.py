@@ -33,18 +33,18 @@ from repro.session import Session, SessionError, print_ppa_report
 
 def serve(arch: str = "qwen3-4b", batch: int = 4, prompt_len: int = 32,
           gen_len: int = 16, numerics: str = "exact", seed: int = 0,
-          params=None, cfg=None, policy=None):
+          params=None, cfg=None, policy=None, reduced: bool = True):
     """Serve ``arch`` (or a ready config + params) through the
     continuous-batching engine and return the greedy continuations as a
     ``(batch, gen_len)`` int array — token-for-token what
     ``Session.generate`` yields for the same seed.  ``numerics`` is a
     preset name; ``policy`` (a NumericsPolicy or a JSON path) overrides
-    it."""
+    it; ``reduced=False`` serves ``arch`` at its published widths."""
     from repro.serving import TierSpec
 
     sess = Session(cfg if cfg is not None else arch,
                    policy=policy if policy is not None else numerics,
-                   seed=seed, params=params)
+                   seed=seed, params=params, reduced=reduced)
     label = "policy" if policy is not None else numerics
     if policy is not None:
         print_ppa_report(sess.ppa_report(), tag="serve")
@@ -64,6 +64,7 @@ def serve(arch: str = "qwen3-4b", batch: int = 4, prompt_len: int = 32,
 
 
 def main(argv=None) -> int:
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import ServingError
 
     ap = argparse.ArgumentParser()
@@ -75,10 +76,14 @@ def main(argv=None) -> int:
     ap.add_argument("--policy", default=None, metavar="POLICY_JSON",
                     help="serve under a per-layer NumericsPolicy (JSON file; "
                          "overrides --numerics)")
+    ap.add_argument("--full-size", action="store_true",
+                    help="use the full arch config (default: reduced)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     try:
         serve(args.arch, batch=args.batch, gen_len=args.gen_len,
-              numerics=args.numerics, policy=args.policy)
+              numerics=args.numerics, policy=args.policy,
+              reduced=not args.full_size)
     except (SessionError, ServingError) as e:
         print(f"error: {e}", file=sys.stderr)
         return 2

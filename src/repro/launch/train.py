@@ -22,6 +22,7 @@ from repro.configs import get_arch
 from repro.data.synthetic import DataConfig, lm_batch
 from repro.distributed.fault import StepWatchdog
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer
 from repro.models.layers import unzip
 
@@ -79,6 +80,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     train(args.arch, steps=args.steps, seq_len=args.seq_len, batch=args.batch,
           ckpt_dir=args.ckpt_dir, reduced=not args.full_config)
 

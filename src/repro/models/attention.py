@@ -37,13 +37,14 @@ NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 
 def gqa_init(key, cfg):
+    dense = partial(dense_init, dtype=jnp.dtype(cfg.param_dtype))
     d, H, KH, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     k1, k2, k3, k4 = jax.random.split(key, 4)
     p = {
-        "wq": dense_init(k1, d, H * hd, ("embed", "q_dim")),
-        "wk": dense_init(k2, d, KH * hd, ("embed", "kv_dim")),
-        "wv": dense_init(k3, d, KH * hd, ("embed", "kv_dim")),
-        "wo": dense_init(k4, H * hd, d, ("q_dim", "embed")),
+        "wq": dense(k1, d, H * hd, ("embed", "q_dim")),
+        "wk": dense(k2, d, KH * hd, ("embed", "kv_dim")),
+        "wv": dense(k3, d, KH * hd, ("embed", "kv_dim")),
+        "wo": dense(k4, H * hd, d, ("q_dim", "embed")),
     }
     if cfg.qk_norm:
         p["q_norm"] = rmsnorm_init(hd)
@@ -396,19 +397,20 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=None, attn_cap=None):
 # ---------------------------------------------------------------------------
 
 def mla_init(key, cfg):
+    dense = partial(dense_init, dtype=jnp.dtype(cfg.param_dtype))
     d, H = cfg.d_model, cfg.n_heads
     m = cfg.mla
     ks = jax.random.split(key, 7)
     qd = m.nope_head_dim + m.rope_head_dim
     return {
-        "wq_a": dense_init(ks[0], d, m.q_lora_rank, ("embed", "q_lora")),
+        "wq_a": dense(ks[0], d, m.q_lora_rank, ("embed", "q_lora")),
         "q_a_norm": rmsnorm_init(m.q_lora_rank),
-        "wq_b": dense_init(ks[1], m.q_lora_rank, H * qd, ("q_lora", "q_dim")),
-        "wkv_a": dense_init(ks[2], d, m.kv_lora_rank + m.rope_head_dim, ("embed", "kv_lora")),
+        "wq_b": dense(ks[1], m.q_lora_rank, H * qd, ("q_lora", "q_dim")),
+        "wkv_a": dense(ks[2], d, m.kv_lora_rank + m.rope_head_dim, ("embed", "kv_lora")),
         "kv_a_norm": rmsnorm_init(m.kv_lora_rank),
-        "wk_b": dense_init(ks[3], m.kv_lora_rank, H * m.nope_head_dim, ("kv_lora", "q_dim")),
-        "wv_b": dense_init(ks[4], m.kv_lora_rank, H * m.v_head_dim, ("kv_lora", "q_dim")),
-        "wo": dense_init(ks[5], H * m.v_head_dim, d, ("q_dim", "embed")),
+        "wk_b": dense(ks[3], m.kv_lora_rank, H * m.nope_head_dim, ("kv_lora", "q_dim")),
+        "wv_b": dense(ks[4], m.kv_lora_rank, H * m.v_head_dim, ("kv_lora", "q_dim")),
+        "wo": dense(ks[5], H * m.v_head_dim, d, ("q_dim", "embed")),
     }
 
 
@@ -513,13 +515,14 @@ def mla_apply(params, x, cfg, spec, positions, cache=None, q_offset=0):
 # ---------------------------------------------------------------------------
 
 def cross_attn_init(key, cfg):
+    dense = partial(dense_init, dtype=jnp.dtype(cfg.param_dtype))
     d, H, hd = cfg.d_model, cfg.n_heads, cfg.resolved_head_dim
     k1, k2, k3, k4 = jax.random.split(key, 4)
     return {
-        "wq": dense_init(k1, d, H * hd, ("embed", "q_dim")),
-        "wk": dense_init(k2, d, H * hd, ("embed", "q_dim")),
-        "wv": dense_init(k3, d, H * hd, ("embed", "q_dim")),
-        "wo": dense_init(k4, H * hd, d, ("q_dim", "embed")),
+        "wq": dense(k1, d, H * hd, ("embed", "q_dim")),
+        "wk": dense(k2, d, H * hd, ("embed", "q_dim")),
+        "wv": dense(k3, d, H * hd, ("embed", "q_dim")),
+        "wo": dense(k4, H * hd, d, ("q_dim", "embed")),
     }
 
 

@@ -91,10 +91,10 @@ def rmsnorm(params, x, eps=1e-6):
 # embeddings / unembedding
 # ---------------------------------------------------------------------------
 
-def embed_init(key, vocab, d, scale=1.0):
+def embed_init(key, vocab, d, scale=1.0, dtype=jnp.float32):
     # vocab-sharded ONLY ('embed_table' never joins the fsdp rule): a 2D-
     # sharded table makes GSPMD all-gather it around the token gather.
-    return PP(normal(key, (vocab, d), scale), ("vocab", "embed_table"))
+    return PP(normal(key, (vocab, d), scale, dtype), ("vocab", "embed_table"))
 
 
 def embed_lookup(table, tokens):
@@ -158,12 +158,12 @@ def apply_rope(x, positions, theta=10000.0, sections=None):
 # gated MLP
 # ---------------------------------------------------------------------------
 
-def mlp_init(key, d, ff):
+def mlp_init(key, d, ff, dtype=jnp.float32):
     k1, k2, k3 = jax.random.split(key, 3)
     return {
-        "wi": dense_init(k1, d, ff, ("embed", "mlp")),
-        "wg": dense_init(k2, d, ff, ("embed", "mlp")),
-        "wo": dense_init(k3, ff, d, ("mlp", "embed")),
+        "wi": dense_init(k1, d, ff, ("embed", "mlp"), dtype),
+        "wg": dense_init(k2, d, ff, ("embed", "mlp"), dtype),
+        "wo": dense_init(k3, ff, d, ("mlp", "embed"), dtype),
     }
 
 

@@ -39,14 +39,15 @@ def moe_init(key, cfg):
     e = cfg.moe
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     scale = d ** -0.5
+    dt = jnp.dtype(cfg.param_dtype)
     p = {
-        "router": dense_init(k1, d, e.n_experts, ("embed", None)),
-        "wi": PP(normal(k2, (e.n_experts, d, ff), scale), ("experts", "embed", "mlp")),
-        "wg": PP(normal(k3, (e.n_experts, d, ff), scale), ("experts", "embed", "mlp")),
-        "wo": PP(normal(k4, (e.n_experts, ff, d), ff ** -0.5), ("experts", "mlp", "embed")),
+        "router": dense_init(k1, d, e.n_experts, ("embed", None), dt),
+        "wi": PP(normal(k2, (e.n_experts, d, ff), scale, dt), ("experts", "embed", "mlp")),
+        "wg": PP(normal(k3, (e.n_experts, d, ff), scale, dt), ("experts", "embed", "mlp")),
+        "wo": PP(normal(k4, (e.n_experts, ff, d), ff ** -0.5, dt), ("experts", "mlp", "embed")),
     }
     if e.n_shared:
-        p["shared"] = mlp_init(k5, d, ff * e.n_shared)
+        p["shared"] = mlp_init(k5, d, ff * e.n_shared, dt)
     return p
 
 

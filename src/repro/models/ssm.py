@@ -33,15 +33,16 @@ def ssm_init(key, cfg):
     ks = jax.random.split(key, 6)
     # fused in_proj: [z, x, B, C, dt]
     proj_out = 2 * d_inner + 2 * N + H
+    dt = jnp.dtype(cfg.param_dtype)
     return {
-        "in_proj": dense_init(ks[0], d, proj_out, ("embed", "ssm_inner")),
-        "conv_w": PP(normal(ks[1], (s.conv_width, d_inner), (s.conv_width) ** -0.5),
+        "in_proj": dense_init(ks[0], d, proj_out, ("embed", "ssm_inner"), dt),
+        "conv_w": PP(normal(ks[1], (s.conv_width, d_inner), (s.conv_width) ** -0.5, dt),
                      ("conv", "ssm_inner")),
         "conv_b": PP(jnp.zeros((d_inner,), jnp.float32), ("ssm_inner",)),
         "A_log": PP(jnp.log(jnp.linspace(1.0, 16.0, H).astype(jnp.float32)), (None,)),
         "dt_bias": PP(jnp.zeros((H,), jnp.float32), (None,)),
         "norm": rmsnorm_init(d_inner)["scale"],
-        "out_proj": dense_init(ks[2], d_inner, d, ("ssm_inner", "embed")),
+        "out_proj": dense_init(ks[2], d_inner, d, ("ssm_inner", "embed"), dt),
     }
 
 

@@ -60,7 +60,7 @@ def block_init(key, cfg, spec):
     if spec.kind == "moe":
         p["mlp"] = moe_mod.moe_init(ks[1], cfg)
     else:
-        p["mlp"] = mlp_init(ks[1], d, cfg.dense_ff)
+        p["mlp"] = mlp_init(ks[1], d, cfg.dense_ff, jnp.dtype(cfg.param_dtype))
     return p
 
 
@@ -393,15 +393,19 @@ def _stack_apply(params, x, cfg, positions, mode, caches=None,
 # ---------------------------------------------------------------------------
 
 def init(cfg, key):
+    """Seeded parameters: every weight of two or more dims is created
+    directly in ``cfg.param_dtype`` (no float32 copy on the way); norm
+    scales and other vectors stay float32."""
     k_emb, k_stack, k_head, k_enc = jax.random.split(key, 4)
+    dt = jnp.dtype(cfg.param_dtype)
     params = {
-        "embed": embed_init(k_emb, cfg.vocab, cfg.d_model),
+        "embed": embed_init(k_emb, cfg.vocab, cfg.d_model, dtype=dt),
         "final_norm": rmsnorm_init(cfg.d_model),
         **stack_params_init(cfg, k_stack),
     }
     if not cfg.tie_embeddings:
         params["unembed"] = dense_init(k_head, cfg.d_model, cfg.vocab,
-                                       ("embed_table", "vocab"))
+                                       ("embed_table", "vocab"), dt)
     if cfg.encoder_layers:
         params["encoder"] = encoder_init(cfg, k_enc)
     return params

@@ -54,11 +54,13 @@ class SessionError(RuntimeError):
 
 # the fast split-float ladder — the default auto-configure candidate set
 # (CPU-cheap calibration; pass candidates="emulated" for the bit-level
-# Pareto-frontier designs of repro.core.sweep.pareto_candidates)
+# Pareto-frontier designs of repro.core.sweep.pareto_candidates).  The
+# "auto" backend runs the Pallas kernel on a TPU and the XLA reference
+# everywhere else.
 SEGMENTED_CANDIDATES: Tuple[Tuple[str, NumericsConfig], ...] = (
-    ("segmented-1", NumericsConfig(mode="segmented", seg_passes=1, backend="xla")),
-    ("segmented-2", NumericsConfig(mode="segmented", seg_passes=2, backend="xla")),
-    ("segmented-3", NumericsConfig(mode="segmented", seg_passes=3, backend="xla")),
+    ("segmented-1", NumericsConfig(mode="segmented", seg_passes=1, backend="auto")),
+    ("segmented-2", NumericsConfig(mode="segmented", seg_passes=2, backend="auto")),
+    ("segmented-3", NumericsConfig(mode="segmented", seg_passes=3, backend="auto")),
 )
 
 # "exact" keeps the arch's own numerics (exact by default); segmented
@@ -731,7 +733,10 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    from repro.launch.compile_cache import enable_compile_cache
+
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     # dryrun lowers the full-size arch by default — its records must be
     # comparable with the launch.dryrun CLI; every other subcommand works
     # on the reduced config unless --full-size

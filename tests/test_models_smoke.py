@@ -150,3 +150,38 @@ def test_numerics_knob_changes_lm_output():
     d = np.abs(np.asarray(h_exact) - np.asarray(h_seg))
     rel = d.mean() / (np.abs(np.asarray(h_exact)).mean() + 1e-9)
     assert 0 < rel < 5e-3, rel
+
+
+def _out_avals(jaxpr):
+    """Every intermediate aval of ``jaxpr``, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _out_avals(sub)
+
+
+def test_init_builds_weights_in_param_dtype_without_float32_copies():
+    """qwen3-4b at published widths stores bf16 weights, drawn directly
+    in bf16: no float32 intermediate outgrows the stacked norm scales.
+    Its checkpoint template (what compat.load_pretrained casts to)
+    follows; the reduced config keeps float32 weights."""
+    from repro.compat.converters import converter_for
+
+    cfg = get_arch("qwen3-4b")
+    assert cfg.param_dtype == "bfloat16"
+    closed = jax.make_jaxpr(lambda k: transformer.init(cfg, k))(
+        jax.random.PRNGKey(0))
+    f32 = [a.size for a in _out_avals(closed.jaxpr)
+           if getattr(a, "dtype", None) == jnp.float32]
+    assert max(f32) <= cfg.n_layers * cfg.d_model
+    tpl, _ = converter_for("qwen3-4b").templates(cfg)
+    big = [a.dtype for a in jax.tree.leaves(tpl) if a.size > max(f32)]
+    assert set(big) == {jnp.dtype(jnp.bfloat16)}
+
+    small = cfg.reduced()
+    params, _ = unzip(transformer.init(small, jax.random.PRNGKey(0)))
+    assert {a.dtype for a in jax.tree.leaves(params)} == {
+        jnp.dtype(jnp.float32)}

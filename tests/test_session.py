@@ -25,8 +25,13 @@ SEG3 = NumericsConfig(mode="segmented", seg_passes=3, backend="xla")
 # ---------------------------------------------------------------------------
 
 def test_session_presets_and_config():
+    from repro.kernels import dispatch
+
     s = Session("qwen3-4b", policy="segmented1")
-    assert s.config.numerics == SEG1
+    # presets leave the kernel to the platform: Pallas on a TPU, the XLA
+    # reference (SEG1's arithmetic) everywhere else
+    assert s.config.numerics == dataclasses.replace(SEG1, backend="auto")
+    assert dispatch.resolve_backend(s.config.numerics.backend) == "xla"
     assert not s.is_policy
     # "exact" keeps the arch's own numerics
     assert Session("qwen3-4b", policy="exact").config.numerics == \
@@ -314,7 +319,18 @@ def test_parse_tiers_spec():
 # serve CLI: thin wrapper + one-line errors, non-zero exit
 # ---------------------------------------------------------------------------
 
-def test_serve_cli_missing_policy_file_exits_nonzero(capsys):
+@pytest.fixture
+def restore_compile_cache_dir():
+    """serve.main points the persistent compile cache at the checkout;
+    these in-process calls compile nothing, so restoring the setting
+    keeps later tests in this worker off the cache."""
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_serve_cli_missing_policy_file_exits_nonzero(
+        capsys, restore_compile_cache_dir):
     from repro.launch import serve
 
     rc = serve.main(["--policy", "/does/not/exist.json", "--batch", "1",
@@ -325,7 +341,8 @@ def test_serve_cli_missing_policy_file_exits_nonzero(capsys):
     assert "cannot read policy file" in err
 
 
-def test_serve_cli_malformed_policy_file_exits_nonzero(tmp_path, capsys):
+def test_serve_cli_malformed_policy_file_exits_nonzero(
+        tmp_path, capsys, restore_compile_cache_dir):
     from repro.launch import serve
 
     bad = tmp_path / "bad.json"
