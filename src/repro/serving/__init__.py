@@ -12,8 +12,8 @@ Design: ``docs/serving.md``.  Scheduling/queueing in
 :mod:`repro.serving.kvcache`, the batching loop in
 :mod:`repro.serving.engine`.
 """
-from repro.serving.engine import (Engine, Event, ModelRunner, TierStats,
-                                  TransformerRunner)
+from repro.serving.engine import (SPANS, Engine, Event, ModelRunner,
+                                  TierStats, TransformerRunner)
 from repro.serving.kvcache import (PageAllocator, ServingError, SlotAllocator,
                                    gather_state, paged_layout,
                                    paged_pool_init, pages_for, scatter_chunk,
@@ -32,6 +32,7 @@ __all__ = [
     "Request",
     "Scheduler",
     "ServingError",
+    "SPANS",
     "SlotAllocator",
     "TierSpec",
     "TierStats",

@@ -37,6 +37,13 @@ Streaming: ``submit(..., on_token=cb)`` fires ``cb(request, token,
 done)`` as tokens land; ``step()`` also returns the step's
 :class:`Event` list for poll-style consumers.
 
+Tracing: each host phase of a step runs under a fixed-name
+:func:`span` (:data:`SPANS`) on the profiler's clock, so a device trace
+can say what the host did in each device idle gap; with the profiler
+off a span costs one flag check.  :class:`TierStats` integrates the
+queue and prefill depths on the engine's clock (``queued_s``,
+``prefilling_s``).
+
 The engine is model-agnostic behind the :class:`ModelRunner` duck type,
 so the scheduler/batching/paging logic is testable with a pure-Python
 stub and no compilation (``tests/serving_sim.py``).
@@ -54,8 +61,22 @@ from repro.serving.kvcache import (PageAllocator, ServingError, SlotAllocator,
 from repro.serving.scheduler import (DEFAULT_TIERS, MonotonicClock, Request,
                                      Scheduler, TierSpec)
 
-__all__ = ["Engine", "Event", "ModelRunner", "TransformerRunner",
+__all__ = ["Engine", "Event", "ModelRunner", "SPANS", "TransformerRunner",
            "TierStats"]
+
+#: The engine's host spans, one per phase of a step (see :func:`span`).
+SPANS = ("engine.admit", "engine.prefill", "engine.batch", "engine.launch",
+         "engine.sync", "engine.land", "engine.retire")
+
+
+def span(name: str):
+    """A host span on the profiler's clock, named by one of :data:`SPANS`
+    (fixed strings: the lane is the enclosing caller's to name).  With the
+    profiler off it records nothing.  jax is imported here, not with the
+    module, so the stub-engine tests load it only when they step."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
 
 
 class ModelRunner:
@@ -188,8 +209,9 @@ class TransformerRunner(ModelRunner):
 
         def _decode(p, tok, pool, tables, pos):
             dense = kvcache.gather_state(pool, self._layout, tables)
-            logits, new = transformer.decode_step(p, cfg, {"token": tok},
-                                                  dense, pos)
+            with jax.named_scope("model"):
+                logits, new = transformer.decode_step(p, cfg, {"token": tok},
+                                                      dense, pos)
             pool = kvcache.scatter_token(pool, self._layout, new, tables,
                                          pos, ps)
             return logits, pool
@@ -229,20 +251,24 @@ class TransformerRunner(ModelRunner):
         def make():
             def _chunk(p, tok, pool, trow, off):
                 dense = kvcache.gather_state(pool, self._layout, trow[None])
-                logits, new = transformer.decode_step(
-                    p, self.cfg, {"token": tok}, dense, off)
+                with jax.named_scope("model"):
+                    logits, new = transformer.decode_step(
+                        p, self.cfg, {"token": tok}, dense, off)
                 pool = kvcache.scatter_chunk(pool, self._layout, new, trow,
                                              off, c, ps)
                 return logits, pool
 
             return jax.jit(_chunk)
 
-        fn = self._jitted(("chunk", c), make)
-        logits, self.pool = fn(
-            self.params, jnp.asarray(prompt[start:end])[None], self.pool,
-            jnp.asarray(table_row, jnp.int32), jnp.asarray(start, jnp.int32))
+        with span("engine.launch"):
+            fn = self._jitted(("chunk", c), make)
+            logits, self.pool = fn(
+                self.params, jnp.asarray(prompt[start:end])[None], self.pool,
+                jnp.asarray(table_row, jnp.int32),
+                jnp.asarray(start, jnp.int32))
         if int(end) == prompt.shape[0]:
-            return int(jnp.argmax(logits[:, -1:], axis=-1)[0, 0])
+            with span("engine.sync"):
+                return int(jnp.argmax(logits[:, -1:], axis=-1)[0, 0])
         return None
 
     def prefill_full(self, slot: int, prompt, table_row):
@@ -270,19 +296,26 @@ class TransformerRunner(ModelRunner):
 
             return jax.jit(_full)
 
-        fn = self._jitted(("full", L), make)
-        logits, self.pool = fn(
-            self.params, jnp.asarray(prompt)[None], self.pool,
-            jnp.asarray(table_row, jnp.int32), jnp.asarray(slot, jnp.int32))
-        return int(jnp.argmax(logits[:, -1:], axis=-1)[0, 0])
+        with span("engine.launch"):
+            fn = self._jitted(("full", L), make)
+            logits, self.pool = fn(
+                self.params, jnp.asarray(prompt)[None], self.pool,
+                jnp.asarray(table_row, jnp.int32),
+                jnp.asarray(slot, jnp.int32))
+        with span("engine.sync"):
+            return int(jnp.argmax(logits[:, -1:], axis=-1)[0, 0])
 
     def decode(self, tokens, pos, tables):
         import jax.numpy as jnp
 
-        logits, self.pool = self._decode(
-            self.params, jnp.asarray(tokens, jnp.int32)[:, None], self.pool,
-            jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32))
-        return np.asarray(jnp.argmax(logits[:, -1:], axis=-1), np.int32)[:, 0]
+        with span("engine.launch"):
+            logits, self.pool = self._decode(
+                self.params, jnp.asarray(tokens, jnp.int32)[:, None],
+                self.pool, jnp.asarray(tables, jnp.int32),
+                jnp.asarray(pos, jnp.int32))
+        with span("engine.sync"):
+            return np.asarray(jnp.argmax(logits[:, -1:], axis=-1),
+                              np.int32)[:, 0]
 
     def zero_pages(self, pages) -> None:
         from repro.serving import kvcache
@@ -321,6 +354,11 @@ class TierStats:
     # steps where active decoders stalled with no decode batch (must stay
     # 0: chunked prefill never preempts a lane's decode)
     n_decode_stall_steps: int = 0
+    # request-seconds on the engine's clock: submitted but not admitted,
+    # and admitted without a first token (over a window: mean queue and
+    # prefill depth; over the window's arrivals: mean queue wait)
+    queued_s: float = 0.0
+    prefilling_s: float = 0.0
 
     @property
     def mean_occupancy(self) -> float:
@@ -373,6 +411,7 @@ class Engine:
         self._step = 0
         self._n_submitted = 0
         self._inflight: dict = {}  # request_id -> Request (queued or active)
+        self._t_depths = self.clock.now()  # queued_s/prefilling_s are to here
 
     # -- construction -------------------------------------------------------
 
@@ -414,7 +453,23 @@ class Engine:
         return tuple(self._lanes)
 
     def lane_stats(self) -> dict:
+        """Each lane's :class:`TierStats`, the depth integrals brought up to
+        now."""
+        self._advance_depths()
         return {name: lane.stats for name, lane in self._lanes.items()}
+
+    def _advance_depths(self) -> float:
+        """Integrate every lane's queue and prefill depth up to now; called
+        before any change of either, at each step's start and by
+        :meth:`lane_stats` (so a snapshot is current).  Returns now."""
+        now = self.clock.now()
+        dt = now - self._t_depths
+        if dt:
+            for name, lane in self._lanes.items():
+                lane.stats.queued_s += dt * self.scheduler.pending(name)
+                lane.stats.prefilling_s += dt * len(lane.prefilling)
+            self._t_depths = now
+        return now
 
     def submit(self, prompt, tier: Optional[str] = None,
                max_new_tokens: int = 16, *, request_id: Optional[str] = None,
@@ -466,7 +521,7 @@ class Engine:
                 f"{lane.runner.page_size}) but tier {tier!r} pools "
                 f"{lane.runner.n_pages} pages")
         self._inflight[rid] = req
-        return self.scheduler.submit(req, self.clock.now())
+        return self.scheduler.submit(req, self._advance_depths())
 
     # -- the serving loop ---------------------------------------------------
 
@@ -483,17 +538,18 @@ class Engine:
         self._emit(events, req, "token", token=int(token))
         # retire on the max-token cap OR the request's EOS stop token
         if req.complete:
-            req.finish_time = self.clock.now()
-            req.finish_step = self._step
-            lane.alloc.free(req.slot)
-            del lane.active[req.slot]
-            freed = lane.pages.release(req.id)
-            lane.runner.zero_pages(freed)
-            req.pages = []
-            lane.stats.pages_reserved_sum += req.n_reserved_pages
-            self._inflight.pop(req.id, None)
-            lane.stats.n_finished += 1
-            self._emit(events, req, "finish")
+            with span("engine.retire"):
+                req.finish_time = self.clock.now()
+                req.finish_step = self._step
+                lane.alloc.free(req.slot)
+                del lane.active[req.slot]
+                freed = lane.pages.release(req.id)
+                lane.runner.zero_pages(freed)
+                req.pages = []
+                lane.stats.pages_reserved_sum += req.n_reserved_pages
+                self._inflight.pop(req.id, None)
+                lane.stats.n_finished += 1
+                self._emit(events, req, "finish")
 
     def _grow_pages(self, lane, req, n_positions: int):
         """Take physical pages (lazily, within the admission reservation)
@@ -530,6 +586,7 @@ class Engine:
         lane.stats.n_prefill_chunks += 1
         if token is None:
             return
+        self._advance_depths()
         del lane.prefilling[req.slot]
         req.pos = L
         lane.active[req.slot] = req
@@ -540,7 +597,7 @@ class Engine:
         every lane -> retire.  Returns the step's events."""
         self._step += 1
         events = []
-        now = self.clock.now()
+        now = self._advance_depths()
         ran_chunks = {}
         # decoders live BEFORE this step's prefill work: the interleave /
         # stall accounting is about what chunked prefill does to them
@@ -550,24 +607,27 @@ class Engine:
             # admit while a row AND the head request's full page
             # reservation fit — head-of-line, so a big request is never
             # starved by smaller queue-jumpers behind it
-            while lane.alloc.n_free and self.scheduler.pending(name):
-                head = self.scheduler.peek_next(name, now)
-                need = head.prompt.shape[0] + head.max_new_tokens - 1
-                n_need = lane.runner.pages_for(need)
-                if not lane.pages.can_reserve(n_need):
-                    break
-                req = self.scheduler.pop_next(name, now)
-                lane.pages.reserve(req.id, n_need)
-                req.n_reserved_pages = n_need
-                req.slot = lane.alloc.alloc(req.id)
-                req.admit_time = now
-                req.admit_step = self._step
-                lane.prefilling[req.slot] = req
-                self._emit(events, req, "admit")
+            with span("engine.admit"):
+                while lane.alloc.n_free and self.scheduler.pending(name):
+                    head = self.scheduler.peek_next(name, now)
+                    need = head.prompt.shape[0] + head.max_new_tokens - 1
+                    n_need = lane.runner.pages_for(need)
+                    if not lane.pages.can_reserve(n_need):
+                        break
+                    t_admit = self._advance_depths()
+                    req = self.scheduler.pop_next(name, now)
+                    lane.pages.reserve(req.id, n_need)
+                    req.n_reserved_pages = n_need
+                    req.slot = lane.alloc.alloc(req.id)
+                    req.admit_time = t_admit  # where queued_s stops
+                    req.admit_step = self._step
+                    lane.prefilling[req.slot] = req
+                    self._emit(events, req, "admit")
             # one prefill chunk per pending prompt, in admission order
             ran_chunks[name] = len(lane.prefilling)
             for req in [lane.prefilling[s] for s in list(lane.prefilling)]:
-                self._prefill_one(events, lane, req)
+                with span("engine.prefill"):
+                    self._prefill_one(events, lane, req)
         for name, lane in self._lanes.items():
             if not lane.active:
                 # a lane whose decoders got no decode batch this step has
@@ -579,24 +639,28 @@ class Engine:
             if ran_chunks[name] and had_active[name]:
                 lane.stats.n_interleave_steps += 1
             runner = lane.runner
-            n = runner.n_slots
-            tokens = np.zeros(n, np.int32)
-            pos = np.zeros(n, np.int32)
-            tables = np.full((n, runner.max_pages), runner.n_pages, np.int32)
-            for slot, req in lane.active.items():
-                # this step writes cache position req.pos — make sure a
-                # physical page covers it (always within the reservation)
-                self._grow_pages(lane, req, req.pos + 1)
-                tokens[slot] = req.tokens[-1]
-                pos[slot] = req.pos
-                tables[slot, :len(req.pages)] = req.pages
+            with span("engine.batch"):
+                n = runner.n_slots
+                tokens = np.zeros(n, np.int32)
+                pos = np.zeros(n, np.int32)
+                tables = np.full((n, runner.max_pages), runner.n_pages,
+                                 np.int32)
+                for slot, req in lane.active.items():
+                    # this step writes cache position req.pos — make sure
+                    # a physical page covers it (always within the
+                    # reservation)
+                    self._grow_pages(lane, req, req.pos + 1)
+                    tokens[slot] = req.tokens[-1]
+                    pos[slot] = req.pos
+                    tables[slot, :len(req.pages)] = req.pages
             nxt = runner.decode(tokens, pos, tables)
             lane.stats.n_decode_steps += 1
             lane.stats.occupancy_sum += len(lane.active)
-            # iterate a snapshot: retirement mutates lane.active
-            for slot, req in sorted(lane.active.items()):
-                req.pos += 1
-                self._land_token(events, lane, req, nxt[slot])
+            with span("engine.land"):
+                # iterate a snapshot: retirement mutates lane.active
+                for slot, req in sorted(lane.active.items()):
+                    req.pos += 1
+                    self._land_token(events, lane, req, nxt[slot])
         return events
 
     @property

@@ -40,6 +40,10 @@ Device-side, the decode/prefill jits move data across the page boundary:
 - :func:`zero_pages` re-zeroes freed pages so a reused page carries no
   bits from its previous occupant.
 
+Their operations carry the named scope ``kv_gather``, ``kv_scatter`` or
+``kv_zero`` in their op metadata, so a device trace tells them from the
+model's own cache slicing (scope ``model``, set by the runner).
+
 Bit-transparency: paging only *relocates* cache rows; gather returns the
 identical values a contiguous buffer would hold, so the decode math — and
 therefore the token stream — is bit-identical to solo generation
@@ -269,11 +273,12 @@ def gather_state(pool, layout, tables):
         s = x.shape
         return x.reshape(s[0], s[1], s[2] * s[3], *s[4:])
 
-    return {"layers": [
-        {pi: (jax.tree.map(g, seg[pi]) if pi in layout[si] else seg[pi])
-         for pi in seg}
-        for si, seg in enumerate(pool["layers"])
-    ]}
+    with jax.named_scope("kv_gather"):
+        return {"layers": [
+            {pi: (jax.tree.map(g, seg[pi]) if pi in layout[si] else seg[pi])
+             for pi in seg}
+            for si, seg in enumerate(pool["layers"])
+        ]}
 
 
 def scatter_token(pool, layout, dense, tables, pos, page_size: int):
@@ -283,18 +288,19 @@ def scatter_token(pool, layout, dense, tables, pos, page_size: int):
     Inactive rows carry null page tables, so their (garbage) row lands in
     the null page.  Per-slot leaves are replaced wholesale by the new
     dense leaves (``decode_step`` already advanced them in place)."""
+    import jax
     import jax.numpy as jnp
-
-    pidx = jnp.take_along_axis(tables, (pos // page_size)[:, None],
-                               axis=1)[:, 0]
-    off = pos % page_size
 
     def upd(pl, dl):
         idx = pos.reshape((1, -1, 1) + (1,) * (dl.ndim - 3))
         val = jnp.take_along_axis(dl, idx, axis=2)[:, :, 0]
         return pl.at[:, pidx, off].set(val.astype(pl.dtype))
 
-    return _map_pairs(pool, layout, dense, upd, lambda pl, dl: dl)
+    with jax.named_scope("kv_scatter"):
+        pidx = jnp.take_along_axis(tables, (pos // page_size)[:, None],
+                                   axis=1)[:, 0]
+        off = pos % page_size
+        return _map_pairs(pool, layout, dense, upd, lambda pl, dl: dl)
 
 
 def scatter_chunk(pool, layout, dense, table_row, start, length: int,
@@ -304,12 +310,8 @@ def scatter_chunk(pool, layout, dense, table_row, start, length: int,
     table, ``(max_pages,)`` int32).  ``length`` is static per compiled
     chunk shape; ``start`` may be traced.  Only valid for fully paged
     layouts (chunked prefill is disabled for SSM hybrids)."""
-    import jax.lax
+    import jax
     import jax.numpy as jnp
-
-    pvec = start + jnp.arange(length)
-    pidx = table_row[pvec // page_size]
-    off = pvec % page_size
 
     def upd(pl, dl):
         val = jax.lax.dynamic_slice_in_dim(dl, start, length, axis=2)[:, 0]
@@ -318,7 +320,11 @@ def scatter_chunk(pool, layout, dense, table_row, start, length: int,
     def slot_leaf(pl, dl):  # unreachable under chunked layouts
         return pl
 
-    return _map_pairs(pool, layout, dense, upd, slot_leaf)
+    with jax.named_scope("kv_scatter"):
+        pvec = start + jnp.arange(length)
+        pidx = table_row[pvec // page_size]
+        off = pvec % page_size
+        return _map_pairs(pool, layout, dense, upd, slot_leaf)
 
 
 def write_state(pool, layout, state, slot, table_row, page_size: int):
@@ -349,13 +355,13 @@ def zero_pages(pool, layout, pages):
     import jax
     import jax.numpy as jnp
 
-    idx = jnp.asarray(pages, jnp.int32)
-
     def z(leaf):
         return leaf.at[:, idx].set(jnp.zeros((), leaf.dtype))
 
-    return {"layers": [
-        {pi: (jax.tree.map(z, seg[pi]) if pi in layout[si] else seg[pi])
-         for pi in seg}
-        for si, seg in enumerate(pool["layers"])
-    ]}
+    with jax.named_scope("kv_zero"):
+        idx = jnp.asarray(pages, jnp.int32)
+        return {"layers": [
+            {pi: (jax.tree.map(z, seg[pi]) if pi in layout[si] else seg[pi])
+             for pi in seg}
+            for si, seg in enumerate(pool["layers"])
+        ]}

@@ -284,6 +284,44 @@ def test_tier_stats_accounting():
     assert s.n_decode_steps == 2 and s.mean_occupancy == 2.0
 
 
+def test_queue_and_prefill_depth_are_exact_request_seconds():
+    """``queued_s`` (submitted, not admitted) and ``prefilling_s``
+    (admitted, no first token) integrate on the engine's clock: a submit
+    to an idle engine is charged nothing for the idle time before it, and
+    a request waiting for a row is charged until its admission."""
+    tiers = (TierSpec("a", priority=0), TierSpec("b", priority=1))
+    eng, clock, _ = make_stub_engine(tiers=tiers, slots=1, prefill_chunk=2)
+
+    def steps_at(*times):
+        for t in times:
+            clock.advance(t - clock.now())
+            eng.step()
+
+    clock.advance(2.0)
+    a = eng.submit(np.arange(1, 6), tier="a", max_new_tokens=2)  # t=2
+    clock.advance(1.0)
+    b = eng.submit(np.array([7, 8]), tier="a", max_new_tokens=2)  # t=3
+    # a: admitted at 3, chunks at 3, 4, 5 (first token at 5), retires at
+    # 5; b waits for the row until 6, and its one chunk lands at once
+    steps_at(3, 4, 5, 6)
+    assert eng.idle
+    clock.advance(2.0)
+    c = eng.submit(np.array([1, 2, 3]), tier="b", max_new_tokens=2)  # t=8
+    clock.advance(1.0)
+    # a snapshot between events is current: c has queued for 1 s
+    assert eng.lane_stats()["b"].queued_s == 1
+    steps_at(10, 11)  # c: admitted at 10, first token at 11
+    assert eng.idle and all(r.done for r in (a, b, c))
+
+    st = eng.lane_stats()
+    assert st["a"].queued_s == (3 - 2) + (6 - 3)
+    assert st["a"].prefilling_s == (5 - 3) + 0
+    assert st["b"].queued_s == 10 - 8
+    assert st["b"].prefilling_s == 11 - 10
+    # the same request-seconds, request by request
+    assert [r.admit_time - r.arrival_time for r in (a, b, c)] == [1, 3, 2]
+
+
 def test_lanes_are_independent_per_tier():
     tiers = (TierSpec("fast", priority=0), TierSpec("slow", priority=1))
     eng, clock, runners = make_stub_engine(tiers=tiers, slots=1)
