@@ -78,6 +78,31 @@ class NumericsConfig:
 EXACT = NumericsConfig(mode="exact")
 
 
+class LayerWeight:
+    """Layer ``index`` of a stacked weight ``stack (L, K, N)``, left
+    unsliced: the segmented Pallas kernel reads that layer's tiles from the
+    stack in place, so a layer scan copies no weight before the call.
+    Every other consumer takes :meth:`value`, the ``(K, N)`` slice."""
+
+    __slots__ = ("stack", "index")
+    ndim = 2
+
+    def __init__(self, stack, index):
+        self.stack, self.index = stack, index
+
+    @property
+    def shape(self):
+        return self.stack.shape[1:]
+
+    @property
+    def dtype(self):
+        return self.stack.dtype
+
+    def value(self) -> jax.Array:
+        return jax.lax.dynamic_index_in_dim(self.stack, self.index,
+                                            keepdims=False)
+
+
 # ---------------------------------------------------------------------------
 # calibration tap — the instrumented-pass hook for repro.core.sensitivity
 # ---------------------------------------------------------------------------
@@ -194,6 +219,9 @@ def nmatmul(x: jax.Array, w: jax.Array, cfg: Optional[NumericsConfig] = None,
             else:
                 resolved = cfg.lookup(path)  # NumericsPolicy / ScopedPolicy
                 # (duck-typed to keep core.numerics import-cycle-free)
+    if isinstance(w, LayerWeight) and (resolved.mode != "segmented"
+                                       or _OPERAND_TAP is not None):
+        w = w.value()
     if _OPERAND_TAP is not None and not (
             isinstance(x, jax.core.Tracer) or isinstance(w, jax.core.Tracer)):
         _OPERAND_TAP(full, x, w)

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.afpm import AFPMConfig
-from repro.core.numerics import BACKENDS
+from repro.core.numerics import BACKENDS, LayerWeight, NumericsConfig
 
 from . import autotune, ref
 from .afpm_bitwise import afpm_bitwise_pallas
@@ -141,15 +141,25 @@ def scan_chunk(backend: str, L: int) -> int:
 
 # -- audited kernel entry points --------------------------------------------
 
+def reads_layers_in_place(ncfg) -> bool:
+    """True when every matmul under ``ncfg`` runs the segmented Pallas
+    kernel (one plain config, not a per-layer policy), which reads a
+    :class:`LayerWeight` from its stack in place."""
+    return (isinstance(ncfg, NumericsConfig) and ncfg.mode == "segmented"
+            and resolve_backend(ncfg.backend) != "xla")
+
+
 def matmul(x, w, passes: int = 3, *, backend: str = "auto",
            block_sizes=None) -> jax.Array:
     """Segmented approximate matmul ``x (..., K) @ w (K, N)``.
 
-    Batched (3-D+) ``x`` runs natively in the Pallas grid (no
-    reshape-flattening of the MXU work); the xla backend is the
-    ``ref.afpm_matmul_ref`` oracle.  Validation and 1-D promotion happen
-    here, before the backend branch, so every backend accepts the same
-    inputs.
+    The leading dims of batched (3-D+) ``x`` fold into the kernel's row
+    axis, so each weight tile is read once per call, not once per batch
+    row; the xla backend is the ``ref.afpm_matmul_ref`` oracle.  ``w``
+    may be a :class:`LayerWeight`, which the kernel reads from its stack
+    in place and the oracle slices.
+    Validation and 1-D promotion happen here, before the backend branch,
+    so every backend accepts the same inputs.
     """
     backend = resolve_backend(backend)
     if x.ndim < 1 or w.ndim != 2:
@@ -160,14 +170,18 @@ def matmul(x, w, passes: int = 3, *, backend: str = "auto",
     if vec:
         x = x[None, :]
     if backend == "xla":
+        if isinstance(w, LayerWeight):
+            w = w.value()
         out = ref.afpm_matmul_ref(x, w, passes)
     else:
         if block_sizes is None:
             block_sizes = matmul_block_sizes(
                 backend, x.shape[-2], x.shape[-1], w.shape[-1])
         bm, bn, bk = block_sizes
-        out = afpm_matmul_pallas(x, w, passes, bm=bm, bn=bn, bk=bk,
-                                 interpret=backend == "interpret")
+        w, layer = ((w.stack, w.index) if isinstance(w, LayerWeight)
+                    else (w, None))
+        out = afpm_matmul_pallas(x, w, passes, layer=layer, bm=bm, bn=bn,
+                                 bk=bk, interpret=backend == "interpret")
     return out[0] if vec else out
 
 

@@ -26,8 +26,8 @@ from . import dispatch
                    static_argnames=("passes", "backend", "force", "interpret"))
 def afpm_matmul(x, w, passes: int = 3, *, backend: str = "auto",
                 force: str | None = None, interpret: bool = False):
-    """Segmented approximate matmul; batch dims on ``x`` run natively in
-    the Pallas grid (no reshape-flattening)."""
+    """Segmented approximate matmul; batch dims on ``x`` fold into the
+    kernel's row axis, which shares each weight tile across them."""
     be = dispatch.resolve_backend(backend, force=force, interpret=interpret)
     return dispatch.matmul(x, w, passes, backend=be)
 

@@ -27,6 +27,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core.numerics import LayerWeight
+from repro.kernels.dispatch import reads_layers_in_place
 from repro.numerics import (current_numerics, expert_paths,
                             force_unroll_active, is_policy, layer_scope,
                             maybe_numerics_scope, nmatmul, numerics_scope,
@@ -215,6 +217,38 @@ def _segment_scannable(ncfg, cfg, pattern, offset, repeats):
     return True
 
 
+def _in_place_weights(ncfg, cfg, pattern, stacked):
+    """``{pi: {path: stack}}``: the stacked 2-D matmul weights of a scanned
+    segment that the segmented kernel reads in place from their stack
+    (:class:`LayerWeight`), so the scan slices no per-layer copy of them.
+    Empty unless every matmul runs the Pallas kernel under one config."""
+    if not reads_layers_in_place(ncfg):
+        return {}
+    out = {}
+    for pi, tree in stacked.items():
+        for site in block_numerics_sites(cfg, pattern[pi]):
+            path, leaf = tuple(site.split(".")), tree
+            for key in path:
+                leaf = leaf.get(key) if isinstance(leaf, dict) else None
+            if getattr(leaf, "ndim", 0) == 3:
+                out.setdefault(pi, {})[path] = leaf
+    return out
+
+
+def _replace_at(tree, path, value):
+    """A copy of the nested dict ``tree`` with the leaf at ``path`` set to
+    ``value`` (dropped if ``value`` is None)."""
+    tree = dict(tree)
+    if len(path) == 1:
+        if value is None:
+            tree.pop(path[0])
+        else:
+            tree[path[0]] = value
+    else:
+        tree[path[0]] = _replace_at(tree[path[0]], path[1:], value)
+    return tree
+
+
 # ---------------------------------------------------------------------------
 # serving-state (cache) construction
 # ---------------------------------------------------------------------------
@@ -349,19 +383,34 @@ def _stack_apply(params, x, cfg, positions, mode, caches=None,
         take_r = lambda tree, r: jax.tree.map(lambda a: a[r], tree)
         if _segment_scannable(ncfg, cfg, pattern, layer_offset, repeats):
             # uniform numerics across repeats: scan (paths resolve with the
-            # segment's first global index — valid exactly because uniform)
-            def seg_body(x, xs, _base=layer_offset):
-                layer_params, layer_caches = xs
+            # segment's first global index — valid exactly because uniform).
+            # Weights the kernel reads in place stay out of the scanned
+            # inputs; the body hands them over as layer r of their stack.
+            in_place = _in_place_weights(ncfg, cfg, pattern, stacked)
+            sliced = dict(stacked)
+            for pi, stacks in in_place.items():
+                for path in stacks:
+                    sliced[pi] = _replace_at(sliced[pi], path, None)
+
+            def seg_body(x, xs, _base=layer_offset, _in_place=in_place):
+                layer_params, layer_caches, r = xs
+                layer_params = dict(layer_params)
+                for pi, stacks in _in_place.items():
+                    for path, stack in stacks.items():
+                        layer_params[pi] = _replace_at(
+                            layer_params[pi], path, LayerWeight(stack, r))
                 return seg_body_at(_base, x, layer_params, layer_caches)
 
             body = _remat(seg_body, cfg)
+            layer_ids = jnp.arange(repeats, dtype=jnp.int32)
             if repeats == 1:
-                x, outc = body(x, ({pi: take_r(v, 0) for pi, v in stacked.items()},
-                                   {pi: take_r(v, 0) for pi, v in seg_caches.items()}))
+                x, outc = body(x, ({pi: take_r(v, 0) for pi, v in sliced.items()},
+                                   {pi: take_r(v, 0) for pi, v in seg_caches.items()},
+                                   layer_ids[0]))
                 outc = {pi: jax.tree.map(lambda a: a[None], v)
                         for pi, v in outc.items()}
             else:
-                x, outc = jax.lax.scan(body, x, (stacked, seg_caches))
+                x, outc = jax.lax.scan(body, x, (sliced, seg_caches, layer_ids))
         else:
             # heterogeneous policy: unroll so each repeat traces its own
             # numerics; caches re-stack to the scanned layout (leading

@@ -1,4 +1,4 @@
-"""The kernel substrate: backend dispatch, tuning tables, batched grids,
+"""The kernel substrate: backend dispatch, tuning tables, batched operands,
 and golden bit-level vectors.
 
 Three layers of guarantees:
@@ -15,6 +15,7 @@ Three layers of guarantees:
    pure-Python integer reference (tests/golden/, regenerate with
    gen_afpm_golden.py).
 """
+import functools
 import json
 import os
 
@@ -24,7 +25,7 @@ import numpy as np
 import pytest
 
 from repro.core.afpm import AFPMConfig, afpm_mult_f32
-from repro.core.numerics import NumericsConfig, nmatmul
+from repro.core.numerics import LayerWeight, NumericsConfig, nmatmul
 from repro.core.registry import get_elementwise, get_multiplier
 from repro.kernels import dispatch, ops, ref
 
@@ -161,23 +162,68 @@ def test_registry_elementwise_backends_agree(rng):
 # backend equivalence: pallas-interpret vs xla-ref, bit-identical
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("shape", [
-    # (lead..., M, K, N): batched and odd/prime extents
-    (37, 43, 29),
-    (5, 37, 43, 29),
-    (2, 3, 17, 33, 9),
+@pytest.mark.parametrize("shape,bm", [
+    # (lead..., M, K, N) and the row block: batched and odd/prime extents
+    pytest.param((37, 43, 29), 37, id="shape0"),
+    pytest.param((5, 37, 43, 29), 37, id="shape1"),
+    pytest.param((2, 3, 17, 33, 9), 17, id="shape2"),
+    # decode shapes: the one-token rows fold into one whole-extent block
+    pytest.param((4, 1, 64, 48), 128, id="decode4"),
+    pytest.param((8, 1, 64, 48), 128, id="decode8"),
+    # 300 folded rows over bm=128: padded to 384, three row blocks
+    pytest.param((3, 100, 64, 48), 128, id="padded_rows"),
 ])
 @pytest.mark.parametrize("passes", [1, 3])
-def test_matmul_interpret_bitwise_equals_xla(shape, passes, rng):
+def test_matmul_interpret_bitwise_equals_xla(shape, bm, passes, rng):
     *lead_mk, N = shape
     x = jnp.asarray(rng.standard_normal(tuple(lead_mk)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((lead_mk[-1], N)), jnp.float32)
     # one contraction block => identical fp32 accumulation order to the oracle
-    blocks = (lead_mk[-2], N, lead_mk[-1])
+    blocks = (bm, N, lead_mk[-1])
     got = dispatch.matmul(x, w, passes, backend="interpret", block_sizes=blocks)
     want = dispatch.matmul(x, w, passes, backend="xla")
     assert got.shape == tuple(lead_mk[:-1]) + (N,)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _pallas_grids(fn, *args):
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    return [e.params["grid_mapping"].grid for e in jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+
+
+def test_matmul_decode_rows_fold_into_one_grid():
+    """A (8, 1, K) decode runs on the 2-D grid (rows/bm, N/bn, K/bk): the
+    eight rows share one row block, so no batch axis walks the weight
+    once per row."""
+    x = jnp.zeros((8, 1, 256), jnp.float32)
+    w = jnp.zeros((256, 96), jnp.float32)
+    fn = functools.partial(dispatch.matmul, passes=1, backend="interpret",
+                           block_sizes=(128, 32, 64))
+    assert _pallas_grids(fn, x, w) == [(1, 3, 4)]
+    assert fn(x, w).shape == (8, 1, 96)
+
+
+@pytest.mark.parametrize("N", [48, 45])  # 45: the layer's tile pads
+def test_matmul_layer_weight_reads_its_stack(N, rng):
+    """A LayerWeight reaches the kernel as the whole stack and its layer
+    index (N a multiple of bn), and the product equals the kernel's on
+    that layer's slice bit for bit; a layer that needs padding is sliced
+    and padded alone, never the stack.  The oracle slices the layer."""
+    stack = jnp.asarray(rng.standard_normal((5, 64, N)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((8, 1, 64)), jnp.float32)
+    fn = functools.partial(dispatch.matmul, passes=3, backend="interpret",
+                           block_sizes=(128, 16, 64))
+    layer = lambda s, i: fn(x, LayerWeight(s, i))  # noqa: E731
+    got = jax.jit(layer)(stack, jnp.int32(3))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(fn(x, stack[3])))
+    jaxpr = jax.make_jaxpr(layer)(stack, jnp.int32(3))
+    call = next(e for e in jaxpr.eqns if e.primitive.name == "pallas_call")
+    assert call.invars[-1].aval.shape == ((5, 64, 48) if N == 48
+                                          else (1, 64, 48))
+    np.testing.assert_array_equal(
+        np.asarray(dispatch.matmul(x, LayerWeight(stack, 3), 3, backend="xla")),
+        np.asarray(dispatch.matmul(x, stack[3], 3, backend="xla")))
 
 
 def test_multiply_broadcasts_on_every_backend(rng):
@@ -251,9 +297,10 @@ def test_ssd_interpret_equals_xla_under_jit(rng):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_ops_batched_matmul_native_grid(rng):
-    """The jit'd wrapper keeps leading batch dims through the native grid
-    (not reshape-flattening) and matches the oracle on every element."""
+def test_ops_batched_matmul_folds_rows(rng):
+    """The jit'd wrapper folds leading batch dims into the kernel's row
+    axis, restores them on the output, and matches the oracle on every
+    element."""
     x = jnp.asarray(rng.standard_normal((3, 2, 48, 45)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((45, 29)), jnp.float32)
     got = ops.afpm_matmul(x, w, 3, backend="interpret")

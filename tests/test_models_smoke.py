@@ -185,3 +185,41 @@ def test_init_builds_weights_in_param_dtype_without_float32_copies():
     params, _ = unzip(transformer.init(small, jax.random.PRNGKey(0)))
     assert {a.dtype for a in jax.tree.leaves(params)} == {
         jnp.dtype(jnp.float32)}
+
+
+@pytest.mark.parametrize("arch", ["qwen3-4b", "mamba2-130m"])
+def test_segmented_decode_reads_layer_weights_in_place(arch, monkeypatch):
+    """Under one segmented config on the Pallas kernel, the layer scan hands
+    each projection to the kernel as a layer of its stack (no per-layer
+    weight copy); the prefill and decode logits are bit-identical to the
+    scan that slices every layer's weights."""
+    import dataclasses
+
+    from repro.core.numerics import NumericsConfig
+
+    cfg = dataclasses.replace(
+        get_arch(arch).reduced(),
+        numerics=NumericsConfig(mode="segmented", seg_passes=3,
+                                backend="interpret"))
+    params, _ = unzip(transformer.init(cfg, jax.random.PRNGKey(4)))
+    repeats, pattern = cfg.segments[0]
+    stacked = {pi: params[f"seg0_p{pi}"] for pi in range(len(pattern))}
+    assert repeats > 1
+    assert transformer._in_place_weights(cfg.numerics, cfg, pattern, stacked)
+    toks = jnp.asarray(np.random.default_rng(5).integers(0, cfg.vocab, (2, 9)),
+                       jnp.int32)
+
+    def run():
+        last, state = transformer.prefill(params, cfg, {"tokens": toks[:, :8]},
+                                          max_len=16)
+        nxt, _ = transformer.decode_step(params, cfg, {"token": toks[:, 8:]},
+                                         state, pos=jnp.int32(8))
+        return np.asarray(last), np.asarray(nxt)
+
+    got = run()
+    monkeypatch.setattr(transformer, "reads_layers_in_place", lambda n: False)
+    assert not transformer._in_place_weights(cfg.numerics, cfg, pattern,
+                                             stacked)
+    want = run()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)

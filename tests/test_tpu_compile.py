@@ -69,7 +69,9 @@ def _sds(one_chip, shape, dtype):
     ((1, 32, D), (D, Q)),         # full prefill chunk
     ((1, 7, D), (D, Q)),          # ragged last chunk
     ((4, 1, D), (D, VOCAB)),      # tied unembed (N not a multiple of 256)
-], ids=["decode_q", "decode_down", "chunk32", "chunk7", "unembed"])
+    ((8, 1, D), (D, FF)),         # bulk decode: MLP up projection, 8 rows
+], ids=["decode_q", "decode_down", "chunk32", "chunk7", "unembed",
+        "bulk_decode_up"])
 def test_afpm_matmul_compiles_for_v5e(one_chip, xs, ws, passes):
     bm, bn, bk = dispatch.matmul_block_sizes("pallas", xs[-2], xs[-1], ws[-1])
     fn = jax.jit(partial(afpm_matmul_pallas, passes=passes, bm=bm, bn=bn,
@@ -113,11 +115,7 @@ def test_ssd_scan_compiles_for_v5e_at_mamba2_130m_widths(one_chip, L):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("mode", ["exact", "segmented"])
-def test_qwen3_4b_decode_step_fits_one_v5e(one_chip, monkeypatch, mode):
-    """One decode step at published widths (batch 4, 256 cache positions)
-    with bf16 weights fits the chip, and the segmented step runs the
-    Pallas kernel (``auto`` resolves to it on a TPU)."""
+def _compiled_decode_step(one_chip, monkeypatch, mode, rows=4):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = dataclasses.replace(
         QWEN, numerics=NumericsConfig(mode=mode, seg_passes=3))
@@ -125,21 +123,45 @@ def test_qwen3_4b_decode_step_fits_one_v5e(one_chip, monkeypatch, mode):
         lambda s: _sds(one_chip, s.shape, s.dtype), tree)
     params, _ = unzip(jax.eval_shape(partial(transformer.init, cfg),
                                      jax.random.PRNGKey(0)))
-    state = jax.eval_shape(partial(transformer.init_state, cfg, 4, 256,
+    state = jax.eval_shape(partial(transformer.init_state, cfg, rows, 256,
                                    dtype=jnp.bfloat16))
-    # every weight is bf16; only the (stacked) norm scales stay float32
-    leaves = jax.tree_util.tree_flatten_with_path(params)[0]
-    assert {jax.tree_util.keystr(k[-1:]) for k, s in leaves
-            if s.dtype != jnp.bfloat16} == {"['scale']"}
 
     def step(p, tok, st, pos):
         return transformer.decode_step(p, cfg, {"token": tok}, st, pos)
 
     compiled = jax.jit(step).lower(
-        place(params), _sds(one_chip, (4, 1), jnp.int32), place(state),
+        place(params), _sds(one_chip, (rows, 1), jnp.int32), place(state),
         _sds(one_chip, (), jnp.int32)).compile()
+    return params, compiled
+
+
+@pytest.mark.parametrize("mode", ["exact", "segmented"])
+def test_qwen3_4b_decode_step_fits_one_v5e(one_chip, monkeypatch, mode):
+    """One decode step at published widths (batch 4, 256 cache positions)
+    with bf16 weights fits the chip, and the segmented step runs the
+    Pallas kernel (``auto`` resolves to it on a TPU)."""
+    params, compiled = _compiled_decode_step(one_chip, monkeypatch, mode)
+    # every weight is bf16; only the (stacked) norm scales stay float32
+    leaves = jax.tree_util.tree_flatten_with_path(params)[0]
+    assert {jax.tree_util.keystr(k[-1:]) for k, s in leaves
+            if s.dtype != jnp.bfloat16} == {"['scale']"}
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, f"{used / 2 ** 30:.2f} GiB"
     has_kernel = "tpu_custom_call" in compiled.as_text()
     assert has_kernel == (mode == "segmented")
+
+
+def test_qwen3_4b_segmented_decode_reads_weights_from_their_stack(
+        one_chip, monkeypatch):
+    """Each of a layer's seven kernel calls takes the whole stack of its
+    weight, ``bf16[36, K, N]``, and the layer index: the layer scan copies
+    no weight out of its stack before the kernel (a bulk decode, 8 rows,
+    one row block)."""
+    _, compiled = _compiled_decode_step(one_chip, monkeypatch, "segmented",
+                                        rows=8)
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    L = QWEN.segments[0][0]
+    assert len(calls) == 7
+    assert all(f"bf16[{L}," in ln and "f32[8," in ln for ln in calls), calls
